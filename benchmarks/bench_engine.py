@@ -30,7 +30,7 @@ Asserted claims:
   engine that ships packed planes (the serial ablation decodes on the
   host, so its h2d traffic is the decoded 12 B/lane either way);
 * every engine on every tier — raw or optimized store, 4-way sharded,
-  Pallas wave kernel (gather variant, interpret mode on this container) —
+  Pallas wave kernel (gather variant; interpreted on the CPU backend) —
   is bit-identical to the single-scan pass on the raw store.
 
 ``REPRO_BENCH_QUICK=1`` (set by ``benchmarks.run --quick``) shrinks the
@@ -70,7 +70,8 @@ SERIAL = dict(decode_on_device=False, overlap=False, fixed_shape=False,
 # The Pallas wave-kernel backend, pinned to the gather variant (what
 # pick_variant chooses at the paper's 16K tiles, and the variant that is
 # bit-identical to the _batch_step engine) so full and quick modes measure
-# the same code path; interpret mode per the CPU-container protocol.
+# the same code path.  The backend decides how it runs: interpreted on
+# the CPU backend, compiled on a TPU (kernels.ops.use_interpreter).
 PALLAS = dict(use_pallas=True, pallas_variant="gather")
 ENGINES = (("serial", SERIAL, 0),
            ("overlapped", {}, 0),

@@ -54,6 +54,7 @@ from repro.apps.pagerank import build_operator, dangling_vertices
 from repro.core.formats import to_chunked
 from repro.io.storage import TileStore
 from repro.net import ClusterFrontDoor
+from repro.net.host import check_local_hosts_allowed
 from repro.runtime import ReplicaSet, ServingFleet, SessionSpec
 from repro.sparse.generate import rmat
 
@@ -230,6 +231,7 @@ def _serve_partitioned(ports: Sequence[int], n: int, spec: SessionSpec,
 
 
 def main() -> List[dict]:
+    check_local_hosts_allowed()
     adj = rmat(SCALE, 8, seed=5)
     op = build_operator(adj)
     ct = to_chunked(op, T=1024, C=128)

@@ -213,6 +213,8 @@ def write_spgemm_json(rows, out_path=None, quick=False) -> str:
 
 
 def main(argv=None) -> int:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma list of name prefixes to run")

@@ -15,11 +15,13 @@ import numpy as np
 
 from repro.apps.common import SEMOperator
 from repro.apps.pagerank import build_operator, dangling_vertices, pagerank
+from repro.compile_cache import enable_compile_cache
 from repro.core.sem import SEMConfig
 from repro.sparse.generate import rmat
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", type=int, default=18,
                     help="log2 #vertices (18 -> 262k vertices, ~4M edges)")

@@ -10,6 +10,7 @@ that make the paper's argument.
 import numpy as np
 
 from repro.apps.common import IMOperator, SEMOperator
+from repro.compile_cache import enable_compile_cache
 from repro.core.formats import CSR, from_coo_tiled
 from repro.core.spmm import spmm_coo
 from repro.sparse.generate import rmat
@@ -18,6 +19,7 @@ import jax.numpy as jnp
 
 
 def main():
+    enable_compile_cache()
     print("== build a scaled power-law graph (R-MAT) ==")
     g = rmat(16, 16, seed=0)  # 65k vertices, ~1M edges
     print(f"graph: {g.n_rows:,} vertices, {g.nnz:,} edges")

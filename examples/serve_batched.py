@@ -9,9 +9,11 @@ dry-run cells.
 """
 import sys
 
+from repro.compile_cache import enable_compile_cache
 from repro.launch.serve import main as serve_main
 
 if __name__ == "__main__":
+    enable_compile_cache()
     argv = sys.argv[1:]
     if not any(a.startswith("--arch") for a in argv):
         argv = ["--arch", "mamba2-130m"] + argv

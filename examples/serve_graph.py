@@ -71,10 +71,12 @@ import numpy as np
 
 from repro.apps.pagerank import (build_operator, dangling_vertices,
                                  pagerank_session)
+from repro.compile_cache import enable_compile_cache
 from repro.core.formats import to_chunked
 from repro.core.sem import SEMConfig
 from repro.io.storage import TileStore
 from repro.net import ClusterFrontDoor
+from repro.net.host import check_local_hosts_allowed
 from repro.runtime import (PowerIterationSession, ReplicaSet, ServingFleet,
                            SessionSpec, SharedScanScheduler)
 from repro.sparse.generate import rmat
@@ -229,6 +231,7 @@ def serve_fleet(adj, replicas, args, raw_nbytes) -> int:
 def serve_cluster(args) -> int:
     """Cross-host serving: N spawned HostServer processes behind one
     ClusterFrontDoor speaking the wire protocol over localhost."""
+    check_local_hosts_allowed()
     adj = rmat(args.scale, 16, seed=1)
     print(f"graph: {adj.n_rows} vertices, {adj.nnz} edges")
     ct = to_chunked(build_operator(adj), T=1024, C=256)
@@ -311,6 +314,7 @@ def serve_cluster(args) -> int:
 
 
 def main() -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", type=int, default=12)
     ap.add_argument("--tenants", type=int, default=6)

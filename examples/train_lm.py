@@ -12,6 +12,7 @@ import argparse
 import shutil
 import tempfile
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import ARCH_IDS, get_config
 from repro.train.data import DataConfig
 from repro.train.loop import TrainConfig, Trainer
@@ -19,6 +20,7 @@ from repro.train.optimizer import AdamWConfig
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="minicpm-2b")
     ap.add_argument("--steps", type=int, default=200)

@@ -44,7 +44,9 @@ carried through every stage, not just the disk read):
   is bit-identical to the scan step, and both share the same staging,
   overlap, h2d accounting, boundary hooks, and sharding.
   ``pallas_variant`` picks gather/VPU vs densify/MXU (``pick_variant`` by
-  default); ``pallas_interpret=False`` compiles for a real TPU.
+  default).  The backend decides how the kernel runs
+  (:func:`repro.kernels.ops.use_interpreter`): compiled on a TPU,
+  interpreted on the CPU backend.
 
 The pass is *elastic*: ``multiply(x, boundary_hook=...)`` invokes the hook
 at every chunk-batch boundary with a :class:`PassBoundary` through which a
@@ -68,6 +70,7 @@ import numpy as np
 from repro.core.semiring import PLUS_TIMES, SEMIRINGS, Semiring
 from repro.io.storage import (DenseStore, GraphHandle, IOStats, TileStore,
                               UpdateBatch)
+from repro.core.decode import decode_planes
 
 # Sentinel for "no per-pass cache override": callers that share one executor
 # (the serving fleet's waves) pass their own budget slice per multiply;
@@ -84,40 +87,15 @@ class SEMConfig:
     use_pallas: bool = False      # Pallas wave kernel as the engine backend
     pallas_variant: Optional[str] = None  # "gather" | "mxu";
     #                               None -> kernels.ops.pick_variant(T)
-    pallas_interpret: bool = True  # interpret mode (the CPU container's
-    #                               protocol); False compiles for the TPU
     decode_on_device: bool = True  # ship uint16 indices, upcast on device
     overlap: bool = True          # stage batch k+1 while batch k computes
     fixed_shape: bool = True      # pad the tail batch to chunk_batch
 
 
-def _decode_planes(meta, row_l, col_l, T: int):
-    """Device mirror of :func:`repro.core.formats.decode_packed_planes`:
-    upcast raw uint16/int32 planes; decode an optimized store's
-    flattened-key deltas (a uint8 column plane marks packing, the row
-    plane's width the 16- vs 24-bit delta mode; chunk bases ride in meta
-    columns 4/5).  The dtype branch resolves at trace time, so the
-    raw-store path keeps the exact jit graph (and cache entry) it had
-    before delta packing existed.  Integer-exact, so raw and packed
-    stores of the same matrix produce bitwise-equal gathers."""
-    if col_l.dtype == jnp.uint8:
-        dk = (row_l.astype(jnp.int32) << 8) | col_l.astype(jnp.int32)
-        k = meta[:, 4:5] * T + meta[:, 5:6] + jnp.cumsum(dk, axis=1)
-        r = k // T
-        c = k - r * T
-        valid = jnp.arange(row_l.shape[1])[None, :] < meta[:, 3:4]
-        r = jnp.where(valid, r, 0)
-        c = jnp.where(valid, c, 0)
-    else:
-        r = row_l.astype(jnp.int32)
-        c = col_l.astype(jnp.int32)
-    return r, c
-
-
 def _scan_batch(meta, row_l, col_l, vals, x_pad, out_blocks, T: int):
     """Trace-time body of the plus-times batch step, shared by the plain
     jit entry and the delta-fused one."""
-    row_l, col_l = _decode_planes(meta, row_l, col_l, T)
+    row_l, col_l = decode_planes(meta, row_l, col_l, T)
     x_blocks = x_pad.reshape(-1, T, x_pad.shape[1])
 
     def step(out, chunk):
@@ -133,7 +111,7 @@ def _scan_batch(meta, row_l, col_l, vals, x_pad, out_blocks, T: int):
 
 def _scan_batch_binary(meta, row_l, col_l, x_pad, out_blocks, T: int):
     """Trace-time body of the binary-matrix batch step."""
-    row_l, col_l = _decode_planes(meta, row_l, col_l, T)
+    row_l, col_l = decode_planes(meta, row_l, col_l, T)
     x_blocks = x_pad.reshape(-1, T, x_pad.shape[1])
     lanes = jnp.arange(row_l.shape[1])
 
@@ -151,7 +129,7 @@ def _scan_batch_binary(meta, row_l, col_l, x_pad, out_blocks, T: int):
 def _scan_batch_ring(meta, row_l, col_l, vals, x_pad, out_blocks, T: int,
                      ring: Semiring):
     """Trace-time body of the general-semiring batch step."""
-    row_l, col_l = _decode_planes(meta, row_l, col_l, T)
+    row_l, col_l = decode_planes(meta, row_l, col_l, T)
     x_blocks = x_pad.reshape(-1, T, x_pad.shape[1])
     lanes = jnp.arange(row_l.shape[1])
     zero = jnp.float32(ring.zero)
@@ -458,8 +436,8 @@ class SEMSpMM:
         store's engine column space (optimized stores persist an operand
         permutation; raw stores pass through).  Skips the rebuild, copy,
         permute, and h2d accounting when ``x`` is already a padded float32
-        device array (the sharded path permutes and stages once for all
-        shards)."""
+        array on this executor's device (the sharded path permutes once
+        and stages once per device)."""
         already_dev = isinstance(x, jax.Array)
         if already_dev and x.shape[0] == self.padded_cols \
                 and x.dtype == jnp.float32:
@@ -470,7 +448,7 @@ class SEMSpMM:
             full[: x.shape[0]] = np.asarray(x, np.float32)
             x_pad = jnp.asarray(self.store.apply_col_perm(full))
             staged = True
-        if self.device is not None:
+        if self.device is not None and x_pad.devices() != {self.device}:
             x_pad = jax.device_put(x_pad, self.device)
             staged = True
         if staged:
@@ -480,15 +458,17 @@ class SEMSpMM:
     def _lane_pad(self, p: int) -> int:
         """Extra dense columns needed to lane-align the Pallas operand:
         the compiled TPU target wants the block width to be a multiple of
-        the 128-lane register width, while interpret mode (and the scan
-        step) accept any p.  Applied on device, once per pass — the padding
-        columns are zeros, contribute zeros, and are sliced off before the
-        result leaves the engine, so they are invisible to callers (and to
-        ``IOStats``: nothing extra crosses the host->device boundary)."""
-        if not self.cfg.use_pallas or self.cfg.pallas_interpret:
+        the 128-lane register width, while the interpreter (and the scan
+        step) accept any p — :func:`repro.kernels.ops.lane_multiple` makes
+        the same backend-driven choice as the kernel.  Applied on device,
+        once per pass — the padding columns are zeros, contribute zeros,
+        and are sliced off before the result leaves the engine, so they are
+        invisible to callers (and to ``IOStats``: nothing extra crosses the
+        host->device boundary)."""
+        if not self.cfg.use_pallas:
             return 0
-        from repro.kernels.ops import LANE
-        return (-p) % LANE
+        from repro.kernels import ops
+        return (-p) % ops.lane_multiple()
 
     def _pad_tail(self, batches: Iterator[Tuple[np.ndarray, ...]],
                   pow2: bool = False
@@ -593,13 +573,12 @@ class SEMSpMM:
         if self.cfg.use_pallas:
             from repro.kernels.ops import pick_variant, spmm_pallas_batch
             variant = self.cfg.pallas_variant or pick_variant(self.T)
-            interpret = self.cfg.pallas_interpret
 
             def step(staged, x_pad, out):
                 meta, nv, rows, cols, vals = staged
                 return spmm_pallas_batch(meta, nv, rows, cols, vals,
                                          x_pad, out, T=self.T,
-                                         variant=variant, interpret=interpret)
+                                         variant=variant)
         elif binary_raw:
             def step(staged, x_pad, out):
                 meta, rows, cols, _ = staged
@@ -891,9 +870,8 @@ class SEMSpMM:
                             constant_values=0.0)
         if acc is None or acc.shape[2] != pw:
             acc = jnp.full((self.n_tile_rows, self.T, pw),
-                           jnp.float32(ring.zero), jnp.float32)
-            if self.device is not None:
-                acc = jax.device_put(acc, self.device)
+                           jnp.float32(ring.zero), jnp.float32,
+                           device=self.device)
         elif ring.is_plus_times():
             acc = _zero_acc(acc)
         else:

@@ -11,8 +11,9 @@ Three tiers, all computing ``out = A @ X`` for sparse ``A`` (n x n) and dense
   working set per step, accumulating each output block locally and writing
   it once.  Pure jnp (lax.scan over chunks); numerically identical to the
   Pallas kernels in ``repro.kernels`` and used as their oracle at scale.
-* ``repro.kernels.ops.spmm_pallas`` — the Pallas kernels (gather/VPU and
-  densify/MXU variants) behind the same chunk layout.
+* ``repro.kernels.ops.spmm_pallas`` — the Pallas wave kernel (gather/VPU
+  and densify/MXU variants) behind the same chunk layout, run as one
+  streaming wave over every chunk.
 
 All paths support generalized semirings except the MXU kernel (plus-times
 only, as on real hardware).

@@ -29,8 +29,8 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.distributed.compat import shard_map
 
 
 # ---------------------------------------------------------------------------

@@ -31,10 +31,8 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from repro.distributed import compat
-from repro.distributed.compat import shard_map
 
 
 def _inv_permute(slot: jax.Array, n_slots: int, n_src: int) -> jax.Array:
@@ -52,7 +50,7 @@ def moe_ffn_a2a(p: dict, xt: jax.Array, *, n_experts: int, top_k: int,
     slices (E_loc, D, F)/(E_loc, F, D).  Returns (out (T_loc, D), aux)."""
     T, D = xt.shape
     E, K = n_experts, top_k
-    m = compat.axis_size(axis)
+    m = jax.lax.axis_size(axis)
     E_loc = E // m
 
     logits = jnp.einsum("td,de->te", xt, p["router"]).astype(jnp.float32)

@@ -29,9 +29,8 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from repro.distributed.compat import shard_map
 
 
 def stage_split(n_layers: int, n_stages: int) -> list:

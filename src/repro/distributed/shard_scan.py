@@ -15,11 +15,13 @@ preserve chunk order, each output row accumulates its contributions in
 exactly the order the single-scan engine uses — the concatenated result is
 bit-identical, not merely allclose.
 
-On this container (one CPU device) shards run on threads: the prefetch
-threads overlap each other's page faults and the per-shard passes release
-the GIL inside XLA compute.  With multiple JAX devices each shard's operand
-and accumulator are pinned round-robin via ``SEMSpMM(device=...)``, turning
-the same code into a one-device-per-shard parallel scan.
+With one JAX device, shards run on threads: the prefetch threads overlap
+each other's page faults and the per-shard passes release the GIL inside
+XLA compute.  With multiple JAX devices (a four-chip TPU host) each
+shard's operand and accumulator are pinned round-robin via
+``SEMSpMM(device=...)``, turning the same code into a one-device-per-shard
+parallel scan; the shared operand is copied from the host to each device
+once per pass.
 
 Two scaling knobs compose here: ``replicas=`` spreads the shards of one
 wave across N copies of the matrix (per-SSD/per-NUMA paths — each shard
@@ -40,7 +42,6 @@ from concurrent.futures import ThreadPoolExecutor
 from typing import List, Optional, Sequence
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 import threading
@@ -217,9 +218,9 @@ class ShardedSEMSpMM:
         coordinator boundary lies inside it), and ``write_columns`` is
         observed through a recording proxy so the writes can be replayed
         onto the operand the held-back shards stream against."""
-        # Pad and stage X once; every shard's ``_prepare_x`` then takes the
-        # already-on-device skip path (and merely re-pins to its own device
-        # when sharded over devices — the one transfer that must repeat).
+        # Pad and relabel X once, then stage it once per device straight
+        # from the host; every shard's ``_prepare_x`` then takes the
+        # already-on-device skip path.
         x = np.asarray(x, np.float32)
         if x.shape[0] != self.padded_cols:
             x_pad = np.zeros((self.padded_cols, x.shape[1]), np.float32)
@@ -227,10 +228,8 @@ class ShardedSEMSpMM:
         else:
             x_pad = x
         # Relabel into an optimized store's engine column space once, for
-        # all shards (no-op on raw stores); each shard's ``_prepare_x``
-        # then takes the already-on-device skip path.
-        x_dev = jnp.asarray(self.store.apply_col_perm(x_pad))
-        self.execs[0].store.stats.add_h2d(x_dev.nbytes)
+        # all shards (no-op on raw stores).
+        x_dev = self._stage_operand(self.store.apply_col_perm(x_pad))
 
         # One delta snapshot for the whole fan-out: shards stream
         # concurrently, and without a shared snapshot an update landing
@@ -252,7 +251,8 @@ class ShardedSEMSpMM:
 
         if boundary_hook is None:
             blocks = list(self._pool.map(
-                lambda iex: iex[1].multiply(x_dev, cache=shard_cache(iex[0]),
+                lambda iex: iex[1].multiply(x_dev[iex[1].device],
+                                            cache=shard_cache(iex[0]),
                                             semiring=semiring, snapshot=snap),
                 enumerate(self.execs)))
         else:
@@ -261,7 +261,7 @@ class ShardedSEMSpMM:
             def recording_hook(b):
                 boundary_hook(_RecordingBoundary(b, writes))
 
-            head = self.execs[0].multiply(x_dev,
+            head = self.execs[0].multiply(x_dev[self.execs[0].device],
                                           boundary_hook=recording_hook,
                                           cache=shard_cache(0),
                                           semiring=semiring, snapshot=snap)
@@ -272,14 +272,26 @@ class ShardedSEMSpMM:
                     x_host[cols.shape[0]:, c0:c0 + cols.shape[1]] = 0.0
                 # writes were recorded in user space; relabel the replayed
                 # operand exactly like the initial staging above
-                x_dev = jnp.asarray(self.store.apply_col_perm(x_host))
-                self.execs[0].store.stats.add_h2d(x_dev.nbytes)
+                x_dev = self._stage_operand(
+                    self.store.apply_col_perm(x_host))
             blocks = [head] + list(self._pool.map(
-                lambda iex: iex[1].multiply(x_dev, cache=shard_cache(iex[0]),
+                lambda iex: iex[1].multiply(x_dev[iex[1].device],
+                                            cache=shard_cache(iex[0]),
                                             semiring=semiring, snapshot=snap),
                 enumerate(self.execs[1:], start=1)))
         self.passes += 1
         return np.concatenate(blocks, axis=0)
+
+    def _stage_operand(self, x_host: np.ndarray) -> dict:
+        """Copy the padded, relabeled operand from the host to every
+        device the shards run on, once per device (one copy for all shards
+        when they share the default device), counting each transfer."""
+        staged = {}
+        for ex in self.execs:
+            if ex.device not in staged:
+                staged[ex.device] = jax.device_put(x_host, ex.device)
+                self.execs[0].store.stats.add_h2d(x_host.nbytes)
+        return staged
 
     def column_bytes(self) -> int:
         """Memory cost of one dense column (input slice + output slice) —

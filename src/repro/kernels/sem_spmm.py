@@ -1,335 +1,237 @@
-"""Pallas TPU kernels for tiled SpMM (the paper's compute hot-spot).
+"""Pallas TPU wave kernel for the semi-external SpMM stream (the paper's
+compute hot-spot).
 
-Two variants, mirroring the paper's SCSR-vs-COO per-tile hybrid (§3.2) —
-there the *storage* format adapts to tile statistics; here the *execution*
-path does:
+One call applies ONE chunk batch of the streaming pass and folds it into a
+running accumulator: ``acc (n_tile_rows*T, p) += A_batch @ X``.  The grid
+is (column block, chunk): the operand's columns split into 128-lane blocks
+(a width that is no multiple of 128 is one block), and for each block one
+step per chunk, chunks sorted by (tile_row, tile_col).  The output
+BlockSpec is indexed by tile_row and column block only, so Pallas keeps
+the output window in VMEM across every chunk of a tile row and writes it
+to HBM once when the tile row changes — the paper's write-once,
+merged-write discipline, enforced by the pipeline structure.  The
+scalar-prefetched ``meta`` array is the static schedule that replaces the
+paper's dynamic task queue.
 
-* :func:`spmm_gather_kernel` — the sparse path.  Per grid step, one chunk of
-  ``C`` non-zeros is resident in VMEM together with one ``(T, p)`` block of X
-  and one ``(T, p)`` output block.  Gather rows of the X block by column
-  index, scale by values, scatter-add by row index.  This is the SCSR
-  analogue: work is O(nnz * p).
-* :func:`spmm_mxu_kernel` — the dense path.  The chunk is first *densified*
-  into the (T, T) tile via a one-hot scatter matmul, then multiplied with the
-  X block on the MXU: ``out += (E_rᵀ · diag(v) · E_c) @ X`` computed as two
-  matmuls ``E_rᵀ @ (v ⊙ (E_c @ X))``.  Work is O(C * T * p) regardless of
-  sparsity — profitable when tiles are dense enough that MXU throughput
-  (~256x the VPU's FLOP rate) beats the gather path's memory-bound walk.
-  This inverts the paper's "register blocking is wasteful for graphs" claim
-  on TPU; see DESIGN.md §2 and the crossover measurement in §Perf.
+Everything the engine's host shim used to do per batch happens on device:
 
-Both use the same grid: one step per chunk, chunks sorted by (tile_row,
-tile_col).  The output BlockSpec is indexed by tile_row only, so Pallas keeps
-the output block in VMEM across every chunk of a tile row and writes it to
-HBM exactly once when the tile row changes — the paper's write-once,
-merged-write discipline, enforced by the pipeline structure.  The scalar-
-prefetched ``meta`` array is the static schedule that replaces the paper's
-dynamic task queue (DESIGN.md §2: LPT-balanced at build time).
+* the index planes arrive as stored (uint16 SCSR lanes, or an optimized
+  store's uint8 deltas) and :func:`repro.core.decode.decode_planes` turns
+  them into int32 lanes inside the same jit — integer-exact, shared with
+  the scan step;
+* a binary matrix streams no value plane; its unit values are synthesized
+  from the chunk nnz (``meta[:, 3]``);
+* first-of-tile-row flags are recomputed from ``meta`` (a batch may start
+  mid-tile-row, so the stored flag ``meta[:, 2]`` is ignored), and the
+  first chunk of a tile row seeds its output window from the accumulator
+  block, which the output aliases (``input_output_aliases``): tile rows the
+  batch never touches keep their accumulated content;
+* the engine's fixed-shape tail pads are skipped via the scalar-prefetched
+  ``n_valid`` count.  ``n_valid`` — not a per-chunk nnz test — is the pad
+  gate because an *empty tile row's* real chunk also has nnz == 0 yet must
+  still run: it opens that row's output window.
 
-Each variant exists in two forms:
+Two variants, mirroring the paper's SCSR-vs-COO per-tile hybrid (§3.2):
 
-* the **one-shot** kernels (:func:`spmm_tiles`) compute ``A @ X`` for a whole
-  matrix in one call.  The stored first-of-tile-row flag (``meta[:, 2]``)
-  zero-initializes each output block, so the output needs no prior content.
-* the **streaming accumulate** kernels (:func:`spmm_tiles_acc`) apply ONE
-  chunk batch of the semi-external pass and fold it into a running
-  accumulator.  Everything the engine's host shim used to do per batch now
-  happens inside the kernel: first-of-tile-row flags are recomputed from the
-  scalar-prefetched ``meta`` (a batch may start mid-tile-row, so the stored
-  flag is wrong and ``meta[:, 2]`` is ignored), the accumulator is both an
-  input (block-indexed like the output) and aliased to the output
-  (``input_output_aliases`` — tile rows the batch never touches keep their
-  accumulated content, visited rows start from it), padded tail chunks are
-  skipped via the scalar-prefetched ``n_valid`` count, and a binary matrix's
-  value lanes are synthesized from the chunk nnz (``meta[:, 3]``) instead of
-  being streamed at all.  ``n_valid`` — not a per-chunk nnz test — is the
-  pad gate because an *empty tile row's* real chunk also has nnz == 0 yet
-  must still run: it opens that row's output window, which must be
-  initialized from the accumulator before the pipeline writes it back.
+* ``gather`` — the sparse path, O(nnz * p).  The chunk's lanes sit in
+  SMEM; a scalar loop over the live lanes loads one X row per lane and
+  scales it into a VMEM scratch, and a second loop adds each product into
+  its output row.  Lanes are row-sorted within a chunk (the store's
+  layout), so each output row accumulates ``0 + c1 + c2 + ...`` and is then
+  added to its running value — the scan step's order, so the two engines
+  agree bit for bit.
+* ``mxu`` — the dense path, O(C * T * p).  The chunk is densified into
+  one-hot matrices, one slab of the tile at a time (the largest power of
+  two up to ``MXU_ROWS`` rows that divides T, so the slabs cover every
+  row), and multiplied on the MXU at f32 precision (``HIGHEST``):
+  ``gathered = E_c @ X`` then ``out += E_r·diag(v) @ gathered``.  It
+  reassociates sums, so it is allclose to the scan step, not bit-identical.
 
-Lowering notes (TPU target): the gather (``jnp.take``) and scatter
-(``.at[].add``) on VMEM blocks lower to per-sublane dynamic gathers; on
-older TPU generations where arbitrary in-VMEM scatter is unsupported, the
-MXU variant is the fallback for every tile.  Kernels are validated in
-interpret mode on CPU (this container) against ``ref.py``.
+Blocks: an X block and the output/accumulator windows are ``(T, bw)``
+with ``bw`` one lane width, so VMEM does not grow with the wave's width;
+the lane planes are ``(C,)`` SMEM rows (gather; C padded to a multiple of
+1024, the TPU's tiling of a 1-D array) or ``(1, C)`` VMEM rows of a
+``(n_chunks, 1, C)`` array (mxu) — both shapes the TPU lowering accepts,
+where a ``(1, C)`` block of an ``(n_chunks, C)`` array is not.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.decode import decode_planes
 
-# ---------------------------------------------------------------------------
-# Per-chunk compute cores (shared by the one-shot and streaming bodies,
-# which differ only in how they scatter/merge the contribution)
-# ---------------------------------------------------------------------------
-def _decode_lanes(meta_ref, g, rows, cols, T: int):
-    """In-kernel decode of one chunk's index lanes, mirroring the engine's
-    ``core.sem._decode_planes`` (and the host's
-    ``formats.decode_packed_planes``) integer for integer: raw uint16/int32
-    lanes upcast; an optimized store's flattened-key deltas decode from
-    the chunk bases in the scalar-prefetched ``meta`` columns 4/5 (a
-    uint8 column plane marks packing, the row plane's width the 16- vs
-    24-bit delta mode; dk = rows << 8 | cols either way).  The dtype
-    branch resolves at trace time, so raw-store callers compile the exact
-    pre-decode kernel."""
-    C = rows.shape[0]
-    if cols.dtype == jnp.uint8:
-        dk = (rows.astype(jnp.int32) << 8) | cols.astype(jnp.int32)
-        k = meta_ref[g, 4] * T + meta_ref[g, 5] + jnp.cumsum(dk)
-        r = k // T
-        c = k - r * T
-        lanes = jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)[:, 0]
-        valid = lanes < meta_ref[g, 3]
-        r = jnp.where(valid, r, 0)
-        c = jnp.where(valid, c, 0)
-    else:
-        r = rows.astype(jnp.int32)
-        c = cols.astype(jnp.int32)
-    return r, c
-
-
-def _gather_contrib(cols, x_ref, vals=None, mask=None):
-    """One chunk's (C, p) scaled gather: rows of the X block by column
-    index, scaled by values — or masked to the live lanes when a binary
-    matrix synthesizes its values on device."""
-    gathered = jnp.take(x_ref[...], cols, axis=0)     # (C, p) VMEM gather
-    if mask is not None:
-        return jnp.where(mask[:, None], gathered, 0.0)
-    return vals[:, None] * gathered
-
-
-def _mxu_blk(rows, cols, vals, x_ref, T: int):
-    """One chunk's dense (T, p) contribution on the MXU:
-    ``E_rᵀ · diag(v) · E_c @ X`` as two one-hot matmuls.  Padding lanes
-    carry val 0, so they contribute nothing."""
-    C = cols.shape[0]
-    # One-hot gather on the MXU: (C, T) @ (T, p).
-    iota_t = jax.lax.broadcasted_iota(jnp.int32, (C, T), 1)
-    e_c = (cols[:, None] == iota_t).astype(x_ref.dtype)
-    gathered = jnp.dot(e_c, x_ref[...],
-                       preferred_element_type=jnp.float32)
-    scaled = vals[:, None] * gathered
-    # One-hot scatter on the MXU: (T, C) @ (C, p).
-    e_r = (rows[:, None] == iota_t).astype(x_ref.dtype)
-    return jnp.dot(e_r.T, scaled, preferred_element_type=jnp.float32)
+LANE = 128       # TPU lane width: VMEM pads a block's last dim to it
+SMEM_TILE = 1024  # TPU tiling of a 1-D 32-bit array: an SMEM block of one
+#                   must hold a whole number of tiles
+MXU_ROWS = 512   # most tile rows per one-hot slab of the MXU variant
 
 
 # ---------------------------------------------------------------------------
 # Kernel bodies
 # ---------------------------------------------------------------------------
-def _gather_body(meta_ref, rows_ref, cols_ref, vals_ref, x_ref, out_ref, *,
-                 T: int):
-    g = pl.program_id(0)
-
-    @pl.when(meta_ref[g, 2] == 1)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    rows, cols = _decode_lanes(meta_ref, g, rows_ref[0], cols_ref[0], T)
-    contrib = _gather_contrib(cols, x_ref, vals=vals_ref[0])
-    out_ref[...] = out_ref[...].at[rows].add(contrib)  # VMEM scatter
-
-
-def _mxu_body(meta_ref, rows_ref, cols_ref, vals_ref, x_ref, out_ref, *,
-              T: int):
-    g = pl.program_id(0)
-
-    @pl.when(meta_ref[g, 2] == 1)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    rows, cols = _decode_lanes(meta_ref, g, rows_ref[0], cols_ref[0], T)
-    blk = _mxu_blk(rows, cols, vals_ref[0], x_ref, T)
-    out_ref[...] = out_ref[...] + blk.astype(out_ref.dtype)
-
-
-# ---------------------------------------------------------------------------
-# Streaming accumulate kernel bodies (one chunk batch of the SEM pass)
-# ---------------------------------------------------------------------------
-def _in_batch_first(meta_ref, g):
-    """First-of-tile-row flag *within this batch*, recomputed on device from
-    the scalar-prefetched meta: the stored flag (``meta[:, 2]``) describes
-    the whole-matrix chunk sequence, but a streaming batch may start
-    mid-tile-row — its first chunk opens a window regardless."""
+def _open_window(meta_ref, g, acc_ref, out_ref):
+    """Seed the output window from the accumulator block at the first chunk
+    of a tile row *within this batch* (``out_ref`` holds garbage until
+    written — the alias guarantees HBM content, not VMEM content)."""
     prev = meta_ref[jnp.maximum(g - 1, 0), 0]
-    return jnp.logical_or(g == 0, meta_ref[g, 0] != prev)
-
-
-def _merge_block(meta_ref, g, acc_ref, out_ref, blk):
-    """Fold one chunk's (T, p) contribution into the output window.  At the
-    first chunk of a tile row the window is seeded from the accumulator
-    block (``out_ref`` holds garbage until written — the alias guarantees
-    HBM content, not VMEM content); afterwards it accumulates in place,
-    mirroring the engine's ``out.at[m[0]].add(blk)`` bit for bit."""
-    first = _in_batch_first(meta_ref, g)
+    first = jnp.logical_or(g == 0, meta_ref[g, 0] != prev)
 
     @pl.when(first)
     def _seed():
-        out_ref[...] = acc_ref[...] + blk
-
-    @pl.when(jnp.logical_not(first))
-    def _accum():
-        out_ref[...] = out_ref[...] + blk
+        out_ref[...] = acc_ref[...]
 
 
-def _live_lanes(meta_ref, g, C):
-    """Binary-matrix lane mask, synthesized on device: a lane is live iff
-    its index < the chunk's nnz (``meta[:, 3]``) — no value plane is ever
-    streamed or staged (TPU note: iota must be >= 2D, hence broadcasted)."""
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)[:, 0]
-    return lanes < meta_ref[g, 3]
-
-
-def _stream_gather_body(meta_ref, nv_ref, *refs, T: int, binary: bool):
-    if binary:
-        rows_ref, cols_ref, x_ref, acc_ref, out_ref = refs
-        vals_ref = None
-    else:
-        rows_ref, cols_ref, vals_ref, x_ref, acc_ref, out_ref = refs
-    g = pl.program_id(0)
+def _gather_body(meta_ref, nv_ref, rows_ref, cols_ref, vals_ref, x_ref,
+                 acc_ref, out_ref, prod_ref):
+    g = pl.program_id(1)
 
     @pl.when(g < nv_ref[0])
     def _step():
-        rows, cols = _decode_lanes(meta_ref, g, rows_ref[0], cols_ref[0], T)
-        if binary:
-            contrib = _gather_contrib(
-                cols, x_ref, mask=_live_lanes(meta_ref, g, cols.shape[0]))
-        else:
-            contrib = _gather_contrib(cols, x_ref, vals=vals_ref[0])
-        blk = jnp.zeros_like(out_ref).at[rows].add(contrib)
-        _merge_block(meta_ref, g, acc_ref, out_ref, blk)
+        _open_window(meta_ref, g, acc_ref, out_ref)
+        nnz = meta_ref[g, 3]
+
+        # Products first, sums second, in separate loops: the scan step
+        # rounds every product before it is added, and one loop body would
+        # let a backend contract ``s + v * x`` into a fused multiply-add.
+        def scale(i, carry):
+            prod_ref[pl.ds(i, 1), :] = (
+                vals_ref[i] * x_ref[pl.ds(cols_ref[i], 1), :]
+            ).astype(prod_ref.dtype)
+            return carry
+
+        def accumulate(i, carry):
+            # (prev row, row value before this chunk, chunk's running sum)
+            prev, base, s = carry
+            r = rows_ref[i]
+            new = r != prev
+            base = jnp.where(new, out_ref[pl.ds(r, 1), :], base)
+            s = (jnp.where(new, 0.0, s)
+                 + prod_ref[pl.ds(i, 1), :].astype(s.dtype))
+            out_ref[pl.ds(r, 1), :] = base + s
+            return r, base, s
+
+        jax.lax.fori_loop(0, nnz, scale, 0)
+        zero = jnp.zeros((1, out_ref.shape[1]), out_ref.dtype)
+        jax.lax.fori_loop(0, nnz, accumulate, (jnp.int32(-1), zero, zero))
 
 
-def _stream_mxu_body(meta_ref, nv_ref, *refs, T: int, binary: bool):
-    if binary:
-        rows_ref, cols_ref, x_ref, acc_ref, out_ref = refs
-        vals_ref = None
-    else:
-        rows_ref, cols_ref, vals_ref, x_ref, acc_ref, out_ref = refs
-    g = pl.program_id(0)
+def _mxu_body(meta_ref, nv_ref, rows_ref, cols_ref, vals_ref, x_ref, acc_ref,
+              out_ref, *, tk: int):
+    g = pl.program_id(1)
 
     @pl.when(g < nv_ref[0])
     def _step():
-        rows, cols = _decode_lanes(meta_ref, g, rows_ref[0], cols_ref[0], T)
-        vals = (_live_lanes(meta_ref, g, cols.shape[0]).astype(x_ref.dtype)
-                if binary else vals_ref[0])
-        blk = _mxu_blk(rows, cols, vals, x_ref, T)
-        _merge_block(meta_ref, g, acc_ref, out_ref, blk.astype(out_ref.dtype))
+        T, p = out_ref.shape
+        C = rows_ref.shape[1]
+        rows, cols = rows_ref[...], cols_ref[...]           # (1, C) lanes
+        live = jax.lax.broadcasted_iota(jnp.int32, (1, C), 1) < meta_ref[g, 3]
+        vals = jnp.where(live, vals_ref[...], 0.0)
+
+        def slab(k):
+            k0 = pl.multiple_of(k * tk, tk)
+            return k0, jax.lax.broadcasted_iota(jnp.int32, (tk, C), 0) + k0
+
+        def gather(k, acc):
+            k0, iota = slab(k)
+            onehot = (iota == cols).astype(x_ref.dtype)     # (tk, C)
+            return acc + jax.lax.dot_general(
+                onehot, x_ref[pl.ds(k0, tk), :], (((0,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
+
+        gathered = jax.lax.fori_loop(0, T // tk, gather,
+                                     jnp.zeros((C, p), jnp.float32))
+        _open_window(meta_ref, g, acc_ref, out_ref)
+
+        def scatter(k, carry):
+            k0, iota = slab(k)
+            w = jnp.where(iota == rows, vals, 0.0)          # E_r·diag(v)
+            blk = jnp.dot(w, gathered, precision=jax.lax.Precision.HIGHEST,
+                          preferred_element_type=jnp.float32)
+            out_ref[pl.ds(k0, tk), :] += blk.astype(out_ref.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, T // tk, scatter, 0)
 
 
 # ---------------------------------------------------------------------------
-# pallas_call wrappers
+# pallas_call wrapper
 # ---------------------------------------------------------------------------
-def _grid_spec(n_chunks: int, C: int, T: int, p: int):
-    return pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_chunks,),
-        in_specs=[
-            pl.BlockSpec((1, C), lambda g, m: (g, 0)),   # rows
-            pl.BlockSpec((1, C), lambda g, m: (g, 0)),   # cols
-            pl.BlockSpec((1, C), lambda g, m: (g, 0)),   # vals
-            pl.BlockSpec((T, p), lambda g, m: (m[g, 1], 0)),  # X block
-        ],
-        out_specs=pl.BlockSpec((T, p), lambda g, m: (m[g, 0], 0)),
-    )
-
-
 def _check_variant(variant: str) -> None:
-    """Fail loudly on a typo'd variant: the dispatch below would otherwise
-    silently fall through to the MXU path (and a caller expecting the
-    gather path's bit-exactness would chase float drift instead)."""
+    """Fail loudly on a typo'd variant: a silent fall-through to the MXU
+    path would make a caller expecting the gather path's bit-exactness
+    chase float drift instead."""
     if variant not in ("gather", "mxu"):
         raise ValueError(f"unknown kernel variant {variant!r}: "
                          "expected 'gather' or 'mxu'")
 
 
-@functools.partial(jax.jit, static_argnames=("T", "n_tile_rows", "variant",
-                                             "interpret"))
-def spmm_tiles(meta, row_local, col_local, vals, x_pad, *, T: int,
-               n_tile_rows: int, variant: str = "gather",
-               interpret: bool = True):
-    """Run the chunked SpMM kernel.  ``x_pad`` is (n_tile_cols * T, p) with
-    p padded to the lane width by the caller; returns (n_tile_rows * T, p)."""
-    _check_variant(variant)
-    n_chunks, C = row_local.shape
-    p = x_pad.shape[1]
-    # Device-side decode: the engine ships the stored index planes as-is.
-    # uint16 upcasts here; uint8 delta planes pass through and cumsum-decode
-    # inside the kernel from the scalar-prefetched meta (jit specializes
-    # per input dtype, so int32 callers compile identically).
-    if row_local.dtype != jnp.uint8:
-        row_local = row_local.astype(jnp.int32)
-    if col_local.dtype != jnp.uint8:
-        col_local = col_local.astype(jnp.int32)
-    body = functools.partial(
-        _gather_body if variant == "gather" else _mxu_body, T=T)
-    return pl.pallas_call(
-        body,
-        grid_spec=_grid_spec(n_chunks, C, T, p),
-        out_shape=jax.ShapeDtypeStruct((n_tile_rows * T, p), x_pad.dtype),
-        interpret=interpret,
-    )(meta, row_local, col_local, vals, x_pad)
-
-
-def _stream_grid_spec(n_chunks: int, C: int, T: int, p: int, binary: bool):
-    """Like :func:`_grid_spec` plus a second scalar-prefetch operand
-    (``n_valid``) and the accumulator input, block-indexed exactly like the
-    output it aliases.  A binary matrix has no value plane at all."""
-    lane_spec = pl.BlockSpec((1, C), lambda g, m, nv: (g, 0))
-
-    def blk_of(col):
-        return pl.BlockSpec((T, p), lambda g, m, nv: (m[g, col], 0))
-    in_specs = [lane_spec, lane_spec]                    # rows, cols
-    if not binary:
-        in_specs.append(lane_spec)                       # vals
-    in_specs += [blk_of(1), blk_of(0)]                   # X block, acc block
-    return pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n_chunks,),
-        in_specs=in_specs,
-        out_specs=blk_of(0),
-    )
-
-
 def spmm_tiles_acc(meta, n_valid, row_local, col_local, vals, x_pad, acc, *,
-                   T: int, variant: str = "gather", interpret: bool = True):
+                   T: int, variant: str, interpret: bool):
     """One SEM chunk batch, fully device-resident: ``acc (n_tile_rows*T, p)
     += A_batch @ x_pad``, returned with only the batch's tile rows changed.
 
-    ``meta`` is the scalar-prefetched schedule (stored first-flags ignored —
-    recomputed in-kernel), ``n_valid (1,) int32`` the count of real chunks
-    (the rest are the engine's fixed-shape tail pads, skipped entirely; a
+    ``meta`` is the scalar-prefetched schedule, ``n_valid (1,) int32`` the
+    count of real chunks (the rest are fixed-shape tail pads, skipped; a
     pad replicates the last real chunk's tile coordinates so it never opens
-    an unseeded output window).  ``vals is None`` denotes a binary matrix
-    whose lanes are synthesized from chunk nnz; uint16 ``row_local`` /
-    ``col_local`` are upcast here, on device.  ``acc`` is aliased to the
-    output: callers hand it over (donate it) and use the result instead."""
+    an unseeded output window).  ``vals is None`` denotes a binary matrix.
+    ``acc`` is aliased to the output: callers hand it over (donate it) and
+    use the result instead.  ``interpret`` runs the kernel through the
+    Pallas interpreter (the CPU backend) instead of compiling it."""
     _check_variant(variant)
     n_chunks, C = row_local.shape
     p = x_pad.shape[1]
-    if row_local.dtype != jnp.uint8:
-        row_local = row_local.astype(jnp.int32)
-    if col_local.dtype != jnp.uint8:
-        col_local = col_local.astype(jnp.int32)
-    binary = vals is None
-    body = functools.partial(
-        _stream_gather_body if variant == "gather" else _stream_mxu_body,
-        T=T, binary=binary)
-    operands = (meta, n_valid, row_local, col_local)
-    if not binary:
-        operands += (vals,)
-    operands += (x_pad, acc)
-    # The alias index counts the scalar-prefetch operands: acc is the last
-    # of `operands`.
+    # Column blocks of one lane width each, so VMEM stays the same however
+    # wide the wave is.  A width that is no multiple of 128 (the engine
+    # lane-pads p when compiled) is a single block.
+    bw = LANE if p % LANE == 0 else p
+    rows, cols = decode_planes(meta, row_local, col_local, T)
+    if vals is None:
+        lanes = jnp.arange(C, dtype=jnp.int32)[None, :]
+        vals = (lanes < meta[:, 3:4]).astype(jnp.float32)
+    planes = (rows, cols, vals.astype(jnp.float32))
+    if variant == "gather":
+        # lanes past C are padding: the lane loops stop at the chunk's nnz
+        cp = C + (-C) % SMEM_TILE
+        planes = tuple(jnp.pad(a, ((0, 0), (0, cp - C))).reshape(-1)
+                       for a in planes)
+        lane_spec = pl.BlockSpec((cp,), lambda j, g, m, nv: (g,),
+                                 memory_space=pltpu.SMEM)
+        body = _gather_body
+        scratch = [pltpu.VMEM((C, bw), jnp.float32)]      # lane products
+    else:
+        planes = tuple(a.reshape(n_chunks, 1, C) for a in planes)
+        lane_spec = pl.BlockSpec((None, 1, C), lambda j, g, m, nv: (g, 0, 0))
+        # slabs tile T exactly: the largest power of two up to MXU_ROWS
+        # that divides it
+        body = functools.partial(_mxu_body, tk=math.gcd(T, MXU_ROWS))
+        scratch = []
+
+    def blk_of(col):
+        return pl.BlockSpec((T, bw), lambda j, g, m, nv: (m[g, col], j))
+
+    # X block + accumulator + output windows, each double-buffered and
+    # lane-padded in VMEM, plus headroom for the gather variant's product
+    # scratch and the MXU variant's one-hot slabs.
+    vmem = 6 * T * max(bw, LANE) * 4 + (16 << 20)
+    operands = (meta, n_valid) + planes + (x_pad, acc)
     return pl.pallas_call(
         body,
-        grid_spec=_stream_grid_spec(n_chunks, C, T, p, binary),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(p // bw, n_chunks),
+            in_specs=[lane_spec] * 3 + [blk_of(1), blk_of(0)],
+            out_specs=blk_of(0),
+            scratch_shapes=scratch),
         out_shape=jax.ShapeDtypeStruct(acc.shape, acc.dtype),
+        # the alias index counts the scalar-prefetch operands: acc is last
         input_output_aliases={len(operands) - 1: 0},
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem),
         interpret=interpret,
     )(*operands)

@@ -48,6 +48,7 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.io.storage import IOStats, TileStore, UpdateBatch
 from repro.net.wire import WireServer
 from repro.runtime.api import Ticket
@@ -350,7 +351,23 @@ def build_host(store_paths: Sequence[str], *, waves: int = 2,
     return HostServer(fleet, host=host, port=port, auth_token=auth_token)
 
 
+def check_local_hosts_allowed() -> None:
+    """Refuse to start local ``repro.net.host`` processes from a process
+    on an accelerator.  Each host process opens its own JAX backend: on the
+    CPU backend any number can, but a chip belongs to one process at a
+    time, and the caller (which has touched JAX) holds it already — the
+    children would fail or hang.  Launchers call this before spawning."""
+    import jax
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            "the multi-process demo needs one chip per host process, and "
+            f"this process already holds the {backend!r} backend; run it "
+            "on the CPU backend (JAX_PLATFORMS=cpu)")
+
+
 def main(argv: Optional[Sequence[str]] = None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         description="Serve one SEM host's fleet over the wire protocol")
     ap.add_argument("--store", action="append", required=True,

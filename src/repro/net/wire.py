@@ -332,6 +332,7 @@ class WireServer:
         self.auth_token = auth_token
         self.rejected_connections = 0
         self._server: Optional[asyncio.base_events.Server] = None
+        self._writers: set = set()   # accepted connections still open
 
     async def start(self) -> int:
         self._server = await asyncio.start_server(
@@ -340,8 +341,13 @@ class WireServer:
         return self.port
 
     async def close(self) -> None:
+        """Stop accepting and close every accepted connection.  Since
+        Python 3.12 ``Server.wait_closed()`` also waits for the accepted
+        connections to close, so they are closed first."""
         if self._server is not None:
             self._server.close()
+            for writer in list(self._writers):
+                writer.close()
             await self._server.wait_closed()
             self._server = None
 
@@ -359,6 +365,14 @@ class WireServer:
 
     async def _serve_connection(self, reader: asyncio.StreamReader,
                                 writer: asyncio.StreamWriter) -> None:
+        self._writers.add(writer)
+        try:
+            await self._serve_frames(reader, writer)
+        finally:
+            self._writers.discard(writer)
+
+    async def _serve_frames(self, reader: asyncio.StreamReader,
+                            writer: asyncio.StreamWriter) -> None:
         if self.auth_token is not None:
             if not await self._authenticate(reader):
                 self.rejected_connections += 1

@@ -329,20 +329,41 @@ def test_pallas_rejects_unknown_variant(valued_path, x8):
               pallas_variant="vpu").multiply(x8)
 
 
-def test_pallas_compiled_mode_lane_aligns_p(valued_path):
-    """pallas_interpret=False targets real TPU lowering, which requires the
-    dense width to be a multiple of the 128 lane register width; the engine
-    pads the operand/accumulator on device and slices the result back.
-    (The compiled lowering itself cannot run on this container — this pins
-    the alignment arithmetic that feeds it.)"""
+def test_pallas_compiled_mode_lane_aligns_p(valued_path, monkeypatch):
+    """On a TPU the kernel is compiled, and the compiled lowering requires
+    the dense width to be a multiple of the 128 lane register width; the
+    engine pads the operand/accumulator on device and slices the result
+    back.  The backend decides (``ops.use_interpreter``); steering that
+    decision here pins the alignment arithmetic the compiled kernel gets,
+    without a program option (the compiled kernel itself cannot run on the
+    CPU backend)."""
+    from repro.kernels import ops
     from repro.kernels.ops import LANE
-    compiled = fresh(valued_path, use_pallas=True, pallas_interpret=False)
+    # the CPU backend interprets the kernel, which (like the scan step)
+    # accepts any width: nothing is padded
+    assert ops.use_interpreter()
+    assert pfresh(valued_path)._lane_pad(8) == 0
+    assert fresh(valued_path)._lane_pad(8) == 0
+    monkeypatch.setattr(ops, "use_interpreter", lambda: False)
+    compiled = pfresh(valued_path)
     assert [compiled._lane_pad(p) for p in (1, 8, 128, 130)] \
         == [127, 120, 0, 126]
     assert all((p + compiled._lane_pad(p)) % LANE == 0 for p in range(1, 300))
-    # interpret mode (this container's protocol) and the scan step pad nothing
-    assert pfresh(valued_path)._lane_pad(8) == 0
-    assert fresh(valued_path)._lane_pad(8) == 0
+    assert fresh(valued_path)._lane_pad(8) == 0   # the scan step never pads
+
+
+def test_pallas_interpreter_is_chosen_by_backend(monkeypatch):
+    """Interpret mode is decided in one place, from the backend: the
+    interpreter on ``cpu``, the compiled kernel on ``tpu``, and an error
+    (not a silent interpreter fallback) anywhere else."""
+    import jax
+    from repro.kernels import ops
+    for backend, want in (("cpu", True), ("tpu", False)):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        assert ops.use_interpreter() is want
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="no Pallas path"):
+        ops.use_interpreter()
 
 
 def test_pallas_sharded_scan_bit_identical(valued_path, x8):

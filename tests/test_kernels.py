@@ -1,5 +1,7 @@
 """Pallas kernel validation: shape/dtype sweeps + hypothesis properties vs
-the ref.py oracle (interpret mode per the CPU-container protocol)."""
+the ref.py oracle.  On the CPU backend the kernel runs in interpret mode
+(the backend decides: ``repro.kernels.ops.use_interpreter``); its TPU
+lowering is compiled in ``test_tpu_compile.py``."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -21,7 +23,9 @@ def _ref(ct, x):
 
 @pytest.mark.parametrize("variant", ["gather", "mxu"])
 @pytest.mark.parametrize("T,C,p", [(128, 32, 1), (256, 64, 3), (256, 128, 8),
-                                   (512, 128, 16)])
+                                   (512, 128, 16),
+                                   # tiles no multiple of the MXU's slab
+                                   (768, 128, 8), (1000, 128, 8)])
 def test_kernel_shape_sweep(small_valued, variant, T, C, p):
     ct = to_chunked(small_valued, T=T, C=C)
     rng = np.random.default_rng(p)
@@ -77,6 +81,27 @@ def test_batch_accumulation(small_valued, variant):
                                 ct.col_local[s:e], ct.vals[s:e], x_pad, out,
                                 T=ct.T, variant=variant)
     got = np.asarray(out.reshape(-1, 3)[: ct.n_rows])
+    np.testing.assert_allclose(got, _ref(ct, x), atol=5e-4)
+
+
+@pytest.mark.parametrize("variant", ["gather", "mxu"])
+def test_wide_wave_column_blocks(small_valued, variant):
+    """A wave wider than one lane width runs as one grid pass per 128-lane
+    column block; batches that start and end mid-tile-row seed every
+    block's output window from the accumulator."""
+    ct = to_chunked(small_valued, T=256, C=64)
+    p = 256
+    rng = np.random.default_rng(4)
+    x = rng.standard_normal((small_valued.n_cols, p)).astype(np.float32)
+    x_pad = jnp.zeros((ct.padded_cols, p)).at[: x.shape[0]].set(x)
+    out = jnp.zeros((ct.n_tile_rows, ct.T, p))
+    B = 50
+    for s in range(0, ct.n_chunks, B):
+        e = min(s + B, ct.n_chunks)
+        out = spmm_pallas_batch(ct.meta[s:e], e - s, ct.row_local[s:e],
+                                ct.col_local[s:e], ct.vals[s:e], x_pad, out,
+                                T=ct.T, variant=variant)
+    got = np.asarray(out.reshape(-1, p)[: ct.n_rows])
     np.testing.assert_allclose(got, _ref(ct, x), atol=5e-4)
 
 
