@@ -71,6 +71,7 @@ from repro.core.semiring import PLUS_TIMES, SEMIRINGS, Semiring
 from repro.io.storage import (DenseStore, GraphHandle, IOStats, TileStore,
                               UpdateBatch)
 from repro.core.decode import decode_planes
+from repro.trace import span
 
 # Sentinel for "no per-pass cache override": callers that share one executor
 # (the serving fleet's waves) pass their own budget slice per multiply;
@@ -336,6 +337,7 @@ class PassBoundary:
         if n_tile_rows <= 0:
             return np.empty((0, c1 - c0), np.float32)
         blk = np.asarray(self.out[:n_tile_rows, :, c0:c1])
+        self.sem.store.stats.add_d2h(blk.nbytes)
         n = min(n_tile_rows * self.sem.T, self.sem.n_rows)
         return blk.reshape(n_tile_rows * self.sem.T, c1 - c0)[:n]
 
@@ -438,22 +440,23 @@ class SEMSpMM:
         permute, and h2d accounting when ``x`` is already a padded float32
         array on this executor's device (the sharded path permutes once
         and stages once per device)."""
-        already_dev = isinstance(x, jax.Array)
-        if already_dev and x.shape[0] == self.padded_cols \
-                and x.dtype == jnp.float32:
-            x_pad = x
-            staged = False
-        else:
-            full = np.zeros((self.padded_cols, x.shape[1]), np.float32)
-            full[: x.shape[0]] = np.asarray(x, np.float32)
-            x_pad = jnp.asarray(self.store.apply_col_perm(full))
-            staged = True
-        if self.device is not None and x_pad.devices() != {self.device}:
-            x_pad = jax.device_put(x_pad, self.device)
-            staged = True
-        if staged:
-            self.store.stats.add_h2d(x_pad.nbytes)
-        return x_pad
+        with span("prepare_x", bytes=4 * self.padded_cols * x.shape[1]):
+            already_dev = isinstance(x, jax.Array)
+            if already_dev and x.shape[0] == self.padded_cols \
+                    and x.dtype == jnp.float32:
+                x_pad = x
+                staged = False
+            else:
+                full = np.zeros((self.padded_cols, x.shape[1]), np.float32)
+                full[: x.shape[0]] = np.asarray(x, np.float32)
+                x_pad = jnp.asarray(self.store.apply_col_perm(full))
+                staged = True
+            if self.device is not None and x_pad.devices() != {self.device}:
+                x_pad = jax.device_put(x_pad, self.device)
+                staged = True
+            if staged:
+                self.store.stats.add_h2d(x_pad.nbytes)
+            return x_pad
 
     def _lane_pad(self, p: int) -> int:
         """Extra dense columns needed to lane-align the Pallas operand:
@@ -534,18 +537,21 @@ class SEMSpMM:
         the Pallas step additionally ships the batch's valid-chunk count
         (one int32 — its 4 bytes are counted too, so ``IOStats.h2d_bytes``
         stays equal to what actually crossed to the device)."""
-        meta, rest = batch[0], batch[1:]
-        dev_rest = tuple(None if a is None else jax.device_put(a, self.device)
-                         for a in rest)
-        dev_meta = jax.device_put(meta, self.device)
-        if self.cfg.use_pallas:
-            nv = jax.device_put(np.asarray([n_valid], np.int32), self.device)
-            staged = (dev_meta, nv) + dev_rest
-        else:
-            staged = (dev_meta,) + dev_rest
-        self.store.stats.add_h2d(
-            sum(a.nbytes for a in staged if a is not None))
-        return staged
+        with span("stage"):
+            meta, rest = batch[0], batch[1:]
+            dev_rest = tuple(None if a is None
+                             else jax.device_put(a, self.device)
+                             for a in rest)
+            dev_meta = jax.device_put(meta, self.device)
+            if self.cfg.use_pallas:
+                nv = jax.device_put(np.asarray([n_valid], np.int32),
+                                    self.device)
+                staged = (dev_meta, nv) + dev_rest
+            else:
+                staged = (dev_meta,) + dev_rest
+            self.store.stats.add_h2d(
+                sum(a.nbytes for a in staged if a is not None))
+            return staged
 
     def _make_step(self, binary_raw: bool, ring: Semiring = PLUS_TIMES):
         """Bind the kernel for this pass: Pallas wave kernel (gather or MXU
@@ -596,9 +602,21 @@ class SEMSpMM:
         returns the possibly-updated operand."""
         if hook is None:
             return x_pad
-        b = PassBoundary(self, chunk_start, x_pad, out)
-        hook(b)
-        return b.x_pad
+        with span("boundary"):
+            b = PassBoundary(self, chunk_start, x_pad, out)
+            hook(b)
+            return b.x_pad
+
+    @staticmethod
+    def _timed_reads(batches: Iterator) -> Iterator:
+        """``batches`` with each ``next`` under a ``read_wait`` span: the
+        time the dispatch thread waits for the store's next batch."""
+        while True:
+            with span("read_wait"):
+                item = next(batches, None)
+            if item is None:
+                return
+            yield item
 
     # -- the delta overlay ---------------------------------------------------
     def _chunk_trow(self) -> np.ndarray:
@@ -805,26 +823,33 @@ class SEMSpMM:
             else:
                 dispatch = self._make_step_delta(step, binary_raw, ring,
                                                  delta_plan)
-            batches = (self._pad_tail(batches, pow2=fragmented)
-                       if self.cfg.fixed_shape else self._with_valid(batches))
-            if not self.cfg.overlap:
-                for i, (batch, nv) in enumerate(batches):
-                    x_pad = self._boundary(hook, starts[i], x_pad, out)
-                    out = dispatch(i, self._stage(batch, nv), x_pad, out)
-            else:
-                pending = None
-                for i, (batch, nv) in enumerate(batches):
-                    staged = self._stage(batch, nv)  # stage k+1 ...
+            batches = self._timed_reads(
+                self._pad_tail(batches, pow2=fragmented)
+                if self.cfg.fixed_shape else self._with_valid(batches))
+            with span("stream"):
+                if not self.cfg.overlap:
+                    for i, (batch, nv) in enumerate(batches):
+                        x_pad = self._boundary(hook, starts[i], x_pad, out)
+                        staged = self._stage(batch, nv)
+                        with span("step"):
+                            out = dispatch(i, staged, x_pad, out)
+                else:
+                    pending = None
+                    for i, (batch, nv) in enumerate(batches):
+                        staged = self._stage(batch, nv)  # stage k+1 ...
+                        if pending is not None:
+                            j, st_j = pending
+                            x_pad = self._boundary(hook, starts[j], x_pad,
+                                                   out)
+                            with span("step"):  # ... while k runs
+                                out = dispatch(j, st_j, x_pad, out)
+                            stats.add_overlap()
+                        pending = (i, staged)
                     if pending is not None:
                         j, st_j = pending
                         x_pad = self._boundary(hook, starts[j], x_pad, out)
-                        out = dispatch(j, st_j, x_pad, out)  # ... while k
-                        stats.add_overlap()
-                    pending = (i, staged)
-                if pending is not None:
-                    j, st_j = pending
-                    x_pad = self._boundary(hook, starts[j], x_pad, out)
-                    out = dispatch(j, st_j, x_pad, out)
+                        with span("step"):
+                            out = dispatch(j, st_j, x_pad, out)
         finally:
             if handle is not None and snap is not None:
                 handle.end_pass()
@@ -878,8 +903,11 @@ class SEMSpMM:
             acc = _fill_acc(acc, float(ring.zero))
         out = self._stream_pass(x_pad, acc, hook=boundary_hook, cache=cache,
                                 ring=ring, snapshot=snapshot)
-        out.block_until_ready()   # only here — never inside the pass
-        result = np.asarray(out.reshape(-1, pw)[: self.n_rows, :p])
+        with span("sync"):
+            out.block_until_ready()   # only here — never inside the pass
+        with span("copyback", bytes=4 * self.n_rows * p):
+            result = np.asarray(out.reshape(-1, pw)[: self.n_rows, :p])
+        self.store.stats.add_d2h(result.nbytes)
         return result, out
 
     # -- regime 3: vertical partitioning ------------------------------------

@@ -66,6 +66,7 @@ class IOStats:
     cache_hit_bytes: int = 0   # bytes served from the hot-chunk cache
                                # instead of the slow tier
     h2d_bytes: int = 0         # host->device bytes staged by the engine
+    d2h_bytes: int = 0         # device->host bytes of results read back
     overlap_batches: int = 0   # batches whose staging overlapped compute
     reads_inflight: int = 0    # slow-tier reads running right now (gauge)
     max_reads_inflight: int = 0  # high-water mark of the gauge
@@ -105,6 +106,10 @@ class IOStats:
     def add_h2d(self, n: int) -> None:
         with self._lock:
             self.h2d_bytes += n
+
+    def add_d2h(self, n: int) -> None:
+        with self._lock:
+            self.d2h_bytes += n
 
     def add_overlap(self, n: int = 1) -> None:
         with self._lock:

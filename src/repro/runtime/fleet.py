@@ -62,6 +62,7 @@ from repro.runtime.api import SubmitterClosed, Ticket, spec_ticket
 from repro.runtime.cache import PartitionedHotChunkCache
 from repro.runtime.scheduler import SharedScanScheduler
 from repro.runtime.session import MultiplyRequest, Session, SessionSpec
+from repro.trace import span
 
 
 class WaveError(RuntimeError):
@@ -234,23 +235,29 @@ class FleetWave:
         return owed + sched.batcher.pending_sessions()
 
     # -- the serving thread --------------------------------------------------
+    def _drained(self) -> bool:
+        """Nothing to serve: the wave thread waits (under ``fleet._cv``)."""
+        return not self._stop and self.scheduler.idle and not self.in_pass
+
     def _serve_loop(self) -> None:
         fleet = self.fleet
         ewma = fleet.ewma
         while True:
             with fleet._cv:
-                while not self._stop and self.scheduler.idle \
-                        and not self.in_pass:
-                    # drained: release this wave's column claim AND its
-                    # cache slice — the arbiter hands both to the busy
-                    # waves (whose next-pass leftover grows to match), so
-                    # the fleet's total pinned bytes never exceed the
-                    # global leftover
-                    fleet._set_wave_cols(self.wave_id, 0)
-                    if fleet.cache is not None:
-                        fleet.cache.set_slice_budget(self.wave_id, 0)
-                    fleet._cv.notify_all()
-                    fleet._cv.wait(timeout=0.5)
+                if self._drained():
+                    with span("wave_wait"):
+                        while self._drained():
+                            # drained: release this wave's column claim
+                            # AND its cache slice — the arbiter hands both
+                            # to the busy waves (whose next-pass leftover
+                            # grows to match), so the fleet's total pinned
+                            # bytes never exceed the global leftover
+                            fleet._set_wave_cols(self.wave_id, 0)
+                            if fleet.cache is not None:
+                                fleet.cache.set_slice_budget(self.wave_id,
+                                                             0)
+                            fleet._cv.notify_all()
+                            fleet._cv.wait(timeout=0.5)
                 if self._stop:
                     fleet._set_wave_cols(self.wave_id, 0)
                     fleet._cv.notify_all()

@@ -72,6 +72,7 @@ from repro.runtime.api import SubmitterClosed, Ticket, spec_ticket
 from repro.runtime.batcher import Batcher, Wave
 from repro.runtime.cache import HotChunkCache, PartitionedHotChunkCache
 from repro.runtime.session import MultiplyRequest, Session, SessionSpec
+from repro.trace import span
 
 
 @dataclasses.dataclass
@@ -261,9 +262,12 @@ class SharedScanScheduler:
             return self._run_pass_ring()
         self._ring_turn = ring_work
         self.pass_no += 1
-        if self.elastic and not self._oversized_head_alone():
-            return self._run_pass_elastic(demand)
-        return self._run_pass_classic(demand)
+        with span("pass", pass_no=self.pass_no,
+                  tenants=len(self.active) + self.batcher.pending,
+                  capacity=self.capacity or 0):
+            if self.elastic and not self._oversized_head_alone():
+                return self._run_pass_elastic(demand)
+            return self._run_pass_classic(demand)
 
     def _pass_boundary_maintenance(self) -> None:
         """Between-pass versioned-graph upkeep: adopt a finished background
@@ -320,7 +324,9 @@ class SharedScanScheduler:
     def _run_pass_classic(self, demand: int) -> Optional[PassReport]:
         col_budget = self.sem.columns_that_fit(demand)
         self.batcher.admit(self.active, col_budget)
-        wave = self.batcher.pack(self.active)
+        with span("pack", bytes=4 * self.sem.n_cols
+                  * sum(s.width for s in self.active)):
+            wave = self.batcher.pack(self.active)
         if wave is None:
             return None
 
@@ -590,11 +596,12 @@ class SharedScanScheduler:
         if not self.active:
             return None
 
-        x = np.zeros((self.sem.n_cols, cap), np.float32)
-        for s in self.active:
-            c0, w = self._slots[s]
-            cols = s.x_columns()
-            x[:, c0:c0 + w] = cols[:, None] if cols.ndim == 1 else cols
+        with span("pack", bytes=4 * self.sem.n_cols * cap):
+            x = np.zeros((self.sem.n_cols, cap), np.float32)
+            for s in self.active:
+                c0, w = self._slots[s]
+                cols = s.x_columns()
+                x[:, c0:c0 + w] = cols[:, None] if cols.ndim == 1 else cols
 
         report = PassReport(wave_cols=sum(w for _, w in self._slots.values()),
                             tenants=len(self.active), capacity=cap)
@@ -624,7 +631,8 @@ class SharedScanScheduler:
         op = self.sharded if self.sharded is not None else self.sem
         y = op.multiply(x, boundary_hook=self._elastic_hook,
                         snapshot=snap)
-        self._pass_end(y, report)
+        with span("deliver", tenants=len(self.active)):
+            self._pass_end(y, report)
         self._finish_report(report, r0, h0, p0)
         return report
 
