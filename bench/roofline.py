@@ -12,6 +12,10 @@ completion on the device): with ``E`` the
 sum of batch nnz times live columns and ``Z`` the sum of batch nnz, the
 window holds ``Z / nnz`` passes, operations ``2 E`` and bytes
 ``8 n E / nnz + Z``.
+
+On a cell of ``k`` chips the work is the same and the chip's peaks count
+``k`` times; the step takes as long as the chip with the most step time,
+which paces the pass.
 """
 from __future__ import annotations
 
@@ -30,17 +34,20 @@ def window_work(run):
 
 
 def bound_seconds(run, E: int, Z: int) -> float:
-    """Least time the chip could take for the booked work: the larger of
-    operations over peak FLOP/s and bytes over peak HBM bandwidth."""
+    """Least time the cell's chips could take for the booked work: the
+    larger of operations over their peak FLOP/s and bytes over their peak
+    HBM bandwidth."""
     ops = 2.0 * E
     nbytes = 8.0 * run.n * E / run.nnz + Z
-    return max(ops / run.peaks["flops_per_s"],
-               nbytes / run.peaks["hbm_bytes_per_s"])
+    return max(ops / (run.chips * run.peaks["flops_per_s"]),
+               nbytes / (run.chips * run.peaks["hbm_bytes_per_s"]))
 
 
 def step_seconds(run) -> Optional[float]:
-    """Device seconds of the batch step's modules in the traced window."""
+    """Device seconds of the batch step's modules in the traced window, on
+    the chip with the most of them."""
     if run.trace is None:
         return None
-    secs = sum(run.trace.module_seconds(STEP_MODULES).values())
+    secs = max(sum(run.trace.module_seconds(STEP_MODULES, chip).values())
+               for chip in range(run.trace.chips))
     return secs if secs > 0 else None

@@ -111,6 +111,7 @@ class RunData:
     t1: float
     n: int                         # graph size
     nnz: int
+    chips: int                     # the cell's chips
     meter: object                  # meter.BatchMeter
     requests: list                 # traffic.Request, in submission order
     attempted: list                # the requests the window owes
@@ -121,6 +122,15 @@ class RunData:
     reports: list                  # PassReports of passes ended in window
     peaks: Optional[dict] = None   # the device's row of peaks.json
     trace: object = None           # trace_reduce.Reduced of the window
+
+
+def memory_peak_bytes(devices) -> Optional[int]:
+    """The largest ``peak_bytes_in_use`` over ``devices`` (the cell's
+    chips), or None where no device reports it."""
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+             for d in devices]
+    peaks = [p for p in peaks if p is not None]
+    return max(peaks) if peaks else None
 
 
 # ---------------------------------------------------------------------------
@@ -253,7 +263,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
                           os.path.join(CACHE, "jax"))
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    device = jax.devices()[0]
+    chips = jax.devices()[:cell.chips]
+    device = chips[0]
     for p in (ROOT, os.path.join(ROOT, "src")):
         if p not in sys.path:
             sys.path.insert(0, p)
@@ -285,16 +296,14 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
                    else 0)
         load = traffic.Load(fleet, mix, pool_t, seed, clients, seconds)
         io0 = IOStats.aggregate([fleet.io_stats])
-        trace_dir = os.path.join(CACHE, "trace")
+        trace_dir = os.path.join(os.path.dirname(workdir), "trace")
         if trace:
             # from before the first pass: the host dispatches a pass's
             # batches long before the device runs them, and the device
             # tracer records only what is launched while it is on
             shutil.rmtree(trace_dir, ignore_errors=True)
-            opts = jax.profiler.ProfileOptions()
-            opts.python_tracer_level = 0
-            opts.host_tracer_level = 1
-            jax.profiler.start_trace(trace_dir, profiler_options=opts)
+            jax.profiler.start_trace(
+                trace_dir, profiler_options=trace_mod.profile_options())
         if mix["loop"] == "closed":
             load.start()
             deadline = time.perf_counter() + DRAIN_S
@@ -312,8 +321,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
         log(f"set-up: fleet of {fl_cfg['n_waves']} wave(s) x "
             f"{fl_cfg['capacity']} columns, warm-up served by "
             f"{time.perf_counter() - t:.3f} s; device peak so far "
-            f"{(device.memory_stats() or {}).get('peak_bytes_in_use')}; "
-            f"host memory {host_memory()}")
+            f"{memory_peak_bytes(chips)}; host memory {host_memory()}")
 
         # -- the window -----------------------------------------------------
         # an open loop's window is its schedule's: it owes exactly the
@@ -331,7 +339,10 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
         reports = [r for w, k in zip(fleet.waves, seen0)
                    for r in w.scheduler.reports[k:]]
         if trace:
+            log(f"trace: host memory {host_memory()} at the window's end")
             jax.profiler.stop_trace()
+            log(f"trace: stopped, {trace_mod.xplane_bytes(trace_dir)} B "
+                f"written; host memory {host_memory()}")
         compiles = counter.between(t0, t1)
 
         # -- drain: every answer the window owes ----------------------------
@@ -343,8 +354,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
         load.stop()
         if load.error is not None:
             raise load.error
-        stats = device.memory_stats() or {}
-        peak = stats.get("peak_bytes_in_use")
+        peak = memory_peak_bytes(chips)
         io1 = IOStats.aggregate([fleet.io_stats])
         passes = fleet.total_scan_passes()
     finally:
@@ -367,6 +377,14 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
         log(f"generator: {len(owed)} requests in flight in the window from "
             f"{clients} closed-loop clients")
     failed = [r for r in owed if r.done is None or r.error is not None]
+    requests, lateness = load.requests, load.lateness
+
+    # the fleet (through the load and the executor) and its tickets go
+    # before the trace is read and the reference's CSR arrives in host
+    # memory
+    del fleet, rs, served, load
+    gc.collect()
+    log(f"host memory with the fleet released: {host_memory()}")
 
     # -- metrics ------------------------------------------------------------
     peaks = None
@@ -380,14 +398,17 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
                                " in peaks.json")
             peaks = table[device.device_kind]
         t = time.perf_counter()
-        reduced = trace_mod.Reduced(trace_mod.extract(trace_dir))
+        reduced = trace_mod.Reduced(trace_mod.extract(trace_dir),
+                                    chips=cell.chips)
         shutil.rmtree(trace_dir, ignore_errors=True)
-        log(f"trace: {reduced.window_s:.3f} s traced, "
-            f"{len(reduced.modules)} device modules, read in "
-            f"{time.perf_counter() - t:.3f} s")
+        log(f"trace: {reduced.window_s:.3f} s traced, {reduced.n_modules} "
+            f"device modules on {reduced.chips} chip(s), busy "
+            f"{[reduced.chip_busy_s(c) for c in range(reduced.chips)]} s, "
+            f"read in {time.perf_counter() - t:.3f} s; host memory "
+            f"{host_memory()}")
     data = RunData(
-        setup_s=setup_s, t0=t0, t1=t1, n=g.n, nnz=g.nnz, meter=meter,
-        requests=load.requests, attempted=owed, lateness=load.lateness,
+        setup_s=setup_s, t0=t0, t1=t1, n=g.n, nnz=g.nnz, chips=cell.chips,
+        meter=meter, requests=requests, attempted=owed, lateness=lateness,
         io={k: v - getattr(io0, k) for k, v in io1.to_dict().items()},
         passes=passes, batches_per_pass=len(meter.sizes),
         reports=reports,
@@ -405,9 +426,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
         f"at {[round(e - t0, 3) for e in meter.pass_ends()]} s from t0")
 
     # -- correct: the sampled answers against the reference ------------------
-    # the fleet (through the load and the executor) and its tickets go
-    # before the reference's CSR arrives in host memory
-    del fleet, rs, served, load, data
+    del data
     gc.collect()
     log(f"host memory before the reference: {host_memory()}")
     t = time.perf_counter()
@@ -441,10 +460,17 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
 
 
 def host_memory() -> str:
-    """This process's peak resident host memory so far."""
+    """This process's peak resident host memory so far, and its resident
+    memory now."""
     import resource
     peak_kb = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss
-    return f"peak {peak_kb / 2**20:.2f} GiB"
+    now = "?"
+    with contextlib.suppress(OSError):
+        with open("/proc/self/status") as f:
+            for line in f:
+                if line.startswith("VmRSS:"):
+                    now = f"{int(line.split()[1]) / 2**20:.2f}"
+    return f"peak {peak_kb / 2**20:.2f} GiB, now {now} GiB"
 
 
 def _finite(x: float) -> float:

@@ -68,10 +68,20 @@ def span_seconds(reduced, name: str) -> float:
 
 
 def idle_by_span(reduced) -> Dict[str, float]:
-    """Idle seconds of the window by the innermost host span covering each
-    idle instant (``none`` where no span covers it).  Innermost is the
-    shortest span covering the instant, so a gap that several spans cover
-    in turn is split among them; the values sum to the idle seconds."""
+    """Idle seconds of the window, every chip's, by the innermost host span
+    covering each idle instant (``none`` where no span covers it).
+    Innermost is the shortest span covering the instant, so a gap that
+    several spans cover in turn is split among them; the values sum to the
+    idle seconds."""
+    out: Dict[str, float] = {}
+    for chip in range(reduced.chips):
+        for name, secs in _split_gaps(reduced,
+                                      reduced.idle_gaps(chip)).items():
+            out[name] = out.get(name, 0.0) + secs
+    return out
+
+
+def _split_gaps(reduced, gaps) -> Dict[str, float]:
     t0, t1 = reduced.t0, reduced.t1
     marks = []  # (time, 0 = a span ends / 1 = starts, duration, name)
     for n, s, d in reduced.host:
@@ -79,7 +89,6 @@ def idle_by_span(reduced) -> Dict[str, float]:
             marks += [(s, 1, d, n), (s + d, 0, d, n)]
     marks.sort()
     marks.append((t1, 0, 0, None))
-    gaps = reduced.idle_gaps()
     out: Dict[str, float] = {}
     live: List[Tuple[int, str]] = []    # heap of (duration, name)
     ended: Dict[Tuple[int, str], int] = {}
@@ -169,10 +178,7 @@ def traced_run(cell, seed: int, seconds: float, log, **kw) -> dict:
         events["host"] += [e[:3] for e in prog]
         seen["args"] = {e[0]: e[3] for e in prog if e[3]}
         seen["n_events"] = len(prog)
-        seen["xplane_bytes"] = sum(
-            os.path.getsize(p) for p in glob.glob(
-                os.path.join(trace_dir, "**", "*.xplane.pb"),
-                recursive=True))
+        seen["xplane_bytes"] = trace_reduce.xplane_bytes(trace_dir)
         return events
 
     def data(**fields):

@@ -71,3 +71,39 @@ def test_traced_rehearsal_reports_the_counters(tmp_path):
         assert m[name]["value"] > 0
     assert "step_roofline.full" not in m
     assert set(result["breakdown"]) == {"device_ops", "idle_gaps"}
+
+
+def test_memory_peak_is_the_fullest_chips():
+    class Dev:
+        def __init__(self, stats):
+            self.stats = stats
+
+        def memory_stats(self):
+            return self.stats
+
+    chips = [Dev({"peak_bytes_in_use": 7}), Dev({"peak_bytes_in_use": 11}),
+             Dev(None), Dev({"bytes_in_use": 3})]
+    assert run.memory_peak_bytes(chips) == 11
+    assert run.memory_peak_bytes(chips[:1]) == 7
+    assert run.memory_peak_bytes(chips[2:]) is None
+
+
+def test_traced_run_asks_for_the_chosen_trace_mode(tmp_path, monkeypatch):
+    import jax
+
+    from bench import trace_reduce
+
+    asked = []
+    start = jax.profiler.start_trace
+
+    def spy(log_dir, **kw):
+        asked.append(kw["profiler_options"])
+        return start(log_dir, **kw)
+
+    monkeypatch.setattr(jax.profiler, "start_trace", spy)
+    result, lines = rehearse("g22-full", 9, tmp_path, trace=True)
+    assert result["correct"], lines
+    assert [o.advanced_configuration for o in asked] == [
+        {"tpu_trace_mode": trace_reduce.TRACE_MODE}]
+    assert asked[0].host_tracer_level == 1
+    assert any("trace: stopped" in ln for ln in lines), lines

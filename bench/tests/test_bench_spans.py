@@ -65,7 +65,7 @@ def test_idle_by_span_agrees_with_a_walk_over_every_instant(seed):
               for _ in range(rng.randrange(0, 8))]
     r = _reduced(host, device)
     want = {}
-    busy = r.busy
+    busy = r.busy[0]
     for t in range(0, 100 * MS, MS):
         if any(a <= t < b for a, b in busy):
             continue
